@@ -42,11 +42,12 @@ def main():
     print(f"[q4] quantized {n_quant} weight matrices to Q4_0 "
           f"({BYTES_PER_ELEM} bytes/element vs 4)")
 
-    # 1) kernel-level: fused Q4 matmul (Pallas, interpret) vs float matmul
+    # 1) kernel-level: fused Q4 matmul (Pallas; interpreted on the CPU) vs
+    #    float matmul
     w = params["period"][0]["mixer"]["wq"][0]          # (d, H*hd)
     qw = quantize_q4_0(jnp.asarray(w).T)
     x = jax.random.normal(jax.random.key(1), (8, w.shape[0]), jnp.float32)
-    y_pallas = q4_matmul(x, qw, interpret=True)
+    y_pallas = q4_matmul(x, qw)
     y_ref = ref.q4_matmul_ref(x, qw)
     y_float = x @ w
     kernel_err = float(jnp.abs(y_pallas - y_ref).max())
@@ -77,7 +78,7 @@ def main():
           f"trained models agree far more)")
 
     # 3) online config tuning (the per-ISA table analogue)
-    tm = TunedMatmul(KernelTuner(alpha=0.3, min_trials=1), interpret=True)
+    tm = TunedMatmul(KernelTuner(alpha=0.3, min_trials=1))
     for _ in range(4):
         tm.q4(x, qw)
     key = ("q4_matmul", shape_class(8, qw.out_features, x.shape[1]))

@@ -61,15 +61,13 @@ from jax.experimental import io_callback
 # each program on the calling thread instead, which composes with nesting,
 # so flip it where the pool is too small for the bridge to be safe.
 if (os.cpu_count() or 1) <= 2:
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except (AttributeError, KeyError):  # jax without the flag
-        pass
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 from repro.core import events as _ev
 from repro.core.hybrid_sim import SimulatedHybridCPU, make_machine
 from repro.core.pool import SubTask, ThreadWorkerPool, VirtualWorkerPool
 from repro.core.tuner import KernelTuner, shape_class
+from repro.device import resolve_interpret
 from repro.quant.q4 import BYTES_PER_ELEM, QuantizedLinear
 from repro.runtime import (
     Balancer,
@@ -216,7 +214,7 @@ class HybridKernelDispatcher:
                  table: Optional[RatioTable] = None, alpha: float = 0.3,
                  tuner: Optional[KernelTuner] = None,
                  sink: Optional[StatsSink] = None, dynamic: bool = True,
-                 interpret: bool = True, keep_stats: bool = True):
+                 interpret: Optional[bool] = None, keep_stats: bool = True):
         self.n_workers = n_workers
         self.machine = machine
         self.table = table or RatioTable(n_workers, alpha=alpha)
@@ -225,7 +223,8 @@ class HybridKernelDispatcher:
         self.tuner = tuner or KernelTuner()
         self.sink = sink
         self.dynamic = dynamic
-        self.interpret = interpret
+        # shard kernels interpret only on the CPU backend (repro.device)
+        self.interpret = resolve_interpret(interpret)
         self.keep_stats = keep_stats
         self.stats: list = []
         self.last_stats: Optional[RegionStats] = None

@@ -20,9 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this under the TPU-prefixed name
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.device import resolve_interpret
 
 __all__ = ["int8_gemm_pallas", "DEFAULT_BLOCKS", "CANDIDATE_BLOCKS"]
 
@@ -45,10 +43,9 @@ def _kernel(a_ref, w_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # MXU int8 path: s32 accumulation.
-    acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32).T,
+    # MXU int8 path: s8 x s8 operands, s32 accumulation.
+    acc_ref[...] += jax.lax.dot_general(
+        a_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
 
@@ -63,11 +60,15 @@ def int8_gemm_pallas(
     w_s8: jax.Array,
     *,
     blocks: tuple[int, int, int] = DEFAULT_BLOCKS,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``a_u8`` (M, K) u8 x ``w_s8`` (N, K) s8 -> (M, N) s32.
 
     M, N, K must be divisible by the block shape (the ops.py wrapper pads).
+    The chip's MXU multiplies signed int8 (a u8 operand is read as s8), so
+    ``a`` enters the kernel shifted into s8 range, ``a - 128`` (its top
+    bit flipped), and the shift comes back as ``128 * sum_k w[n, k]``:
+    exact, since every term stays far inside s32.
     """
     m, k = a_u8.shape
     n, k2 = w_s8.shape
@@ -76,7 +77,9 @@ def int8_gemm_pallas(
     bm, bn, bk = blocks
     if m % bm or n % bn or k % bk:
         raise ValueError(f"shape ({m},{n},{k}) not divisible by blocks {blocks}")
-    return pl.pallas_call(
+    a_s8 = jax.lax.bitcast_convert_type(a_u8 ^ jnp.uint8(0x80), jnp.int8)
+    shift = 128 * jnp.sum(w_s8, axis=1, dtype=jnp.int32)
+    out = pl.pallas_call(
         _kernel,
         grid=(m // bm, n // bn, k // bk),
         in_specs=[
@@ -86,8 +89,9 @@ def int8_gemm_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=interpret,
-    )(a_u8, w_s8)
+        interpret=resolve_interpret(interpret),
+    )(a_s8, w_s8)
+    return out + shift[None, :]

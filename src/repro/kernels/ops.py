@@ -6,8 +6,9 @@ Responsibilities:
     :class:`repro.core.tuner.KernelTuner` (the paper's per-ISA performance
     table, re-keyed by (kernel, shape-class)), falling back to defaults when
     no tuner is supplied;
-  * backend selection — ``interpret=True`` runs the kernel body on CPU
-    (validation); on TPU hardware the same call lowers to Mosaic.
+  * backend selection — ``interpret=None`` (the default) resolves through
+    :func:`repro.device.resolve_interpret`: the kernel body is interpreted
+    on the CPU backend only and lowers to Mosaic on the chip.
 """
 
 from __future__ import annotations
@@ -45,7 +46,7 @@ def int8_gemm(
     w_s8: jax.Array,
     *,
     blocks: tuple[int, int, int] = _i8.DEFAULT_BLOCKS,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """u8 (M,K) x s8 (N,K) -> s32 (M,N), padding to block multiples.
 
@@ -66,7 +67,7 @@ def int8_linear(
     w: QuantizedWeightI8,
     *,
     blocks: tuple[int, int, int] = _i8.DEFAULT_BLOCKS,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Full quantized linear (u8s8 -> s32 -> dequant f32)."""
     acc = int8_gemm(a.q, w.q, blocks=blocks, interpret=interpret)
@@ -78,7 +79,7 @@ def q4_matmul(
     qw: QuantizedLinear,
     *,
     blocks: tuple[int, int, int] = _q4.DEFAULT_BLOCKS,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """f32/bf16 (M,K) x Q4_0 (N,K) -> (M,N), padding M/N to block multiples.
 
@@ -112,7 +113,8 @@ class TunedMatmul:
     online — per-(kernel, shape-class) EMA argmin, the paper's table re-keyed.
     """
 
-    def __init__(self, tuner: Optional[KernelTuner] = None, interpret: bool = False):
+    def __init__(self, tuner: Optional[KernelTuner] = None,
+                 interpret: Optional[bool] = None):
         self.tuner = tuner or KernelTuner()
         self.interpret = interpret
 

@@ -37,7 +37,9 @@ def init_cluster(coordinator: Optional[str] = None,
     """Initialize jax.distributed if a multi-host environment is detected.
 
     Returns True when distributed mode is active.  Safe to call on a
-    single host (no-op).
+    single host (no-op).  A detected cluster whose initialization fails
+    raises: carrying on as one host would train or serve on a fraction of
+    the devices the job was given.
     """
     import jax
 
@@ -51,16 +53,12 @@ def init_cluster(coordinator: Optional[str] = None,
                 "SLURM_JOB_ID"))
     if coordinator is None and not auto:
         return False
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-        return True
-    except Exception as e:  # pragma: no cover - depends on environment
-        print(f"[cluster] distributed init failed ({e}); single-host mode")
-        return False
+    jax.distributed.initialize(
+        coordinator_address=coordinator,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
+    return True
 
 
 def _env_int(name: str) -> Optional[int]:

@@ -10,7 +10,10 @@ Modes:
   to replicas by measured per-phase throughput; each replica interleaves
   chunked prefill with its running decode batch.  ``--machine`` drives a
   deterministic virtual clock from the paper's hybrid-CPU model (per-phase
-  core dispatch); ``--machine wall`` uses real wall time.
+  core dispatch); ``--machine wall`` uses real wall time, and is the
+  default on an accelerator.  ``--preset full --layers N`` serves the
+  published widths cut to ``N`` layers; with several devices, replica
+  ``i`` lives on device ``i`` modulo their number.
 * ``--legacy-batch`` — the seed-era whole-batch path (one
   ``RoutedServer.serve_batch`` round), kept for migration comparisons.
 * ``--fleet`` — cluster-scale serving: a default heterogeneous fleet
@@ -24,6 +27,7 @@ Modes:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -33,6 +37,7 @@ from repro.configs import get_config, reduced_config
 from repro.core import events as _ev
 from repro.core.hybrid_sim import MACHINES
 from repro.core.tuner import KernelTuner, TunerStore
+from repro.device import device_info, enable_compile_cache
 from repro.kernels import (
     GEMV_ISA,
     TRUNK_KINDS,
@@ -134,15 +139,23 @@ def run_fleet_mode(args, cfg, params, max_seq: int, registry=None) -> int:
     return 0
 
 
-def main() -> int:
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and reconcile the serve options (``--topology`` implies a
+    balanced trunk and brings its own virtual clock)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--preset", choices=["tiny", "full"], default="tiny")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep this many layers of the preset (a depth cut; "
+                         "every width stays as the preset has it)")
     ap.add_argument("--batch", type=int, default=4,
                     help="total concurrent-request slots across replicas")
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--steps", type=int, default=32,
                     help="max new tokens per request")
+    ap.add_argument("--max-seq", type=int, default=None,
+                    help="KV-cache positions per slot (default: prompt "
+                         "length + steps + 8)")
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--rate", type=float, default=50.0,
@@ -151,8 +164,9 @@ def main() -> int:
                     help="prompt tokens prefilled per iteration (0: one-shot)")
     ap.add_argument("--machine", default=None,
                     choices=sorted(MACHINES) + ["wall"],
-                    help="virtual hybrid-CPU clock (default ultra-125h), "
-                         "or 'wall' for real time")
+                    help="virtual hybrid-CPU clock, or 'wall' for real "
+                         "time (default: ultra-125h on the CPU backend, "
+                         "wall on an accelerator)")
     ap.add_argument("--topology", default=None,
                     choices=sorted(TOPOLOGIES) + sorted(MACHINES),
                     help="serve on a NUMA topology: the balanced trunk "
@@ -161,7 +175,9 @@ def main() -> int:
                          "on the flattened machine; implies "
                          "--balanced-trunk (flat machine names are the "
                          "1-socket special case)")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the random weights, the traffic and the "
+                         "virtual clocks")
     ap.add_argument("--ratios", default=None,
                     help="JSON path to warm-start/persist replica ratios")
     ap.add_argument("--legacy-batch", action="store_true",
@@ -189,6 +205,12 @@ def main() -> int:
                     default="q4",
                     help="balanced-trunk weight path: Q4_0 Pallas GEMV, "
                          "dynamic-u8xs8 INT8 GEMM, or shard-exact fp32")
+    ap.add_argument("--trunk-mode", choices=["bridge", "compiled"],
+                    default="bridge",
+                    help="balanced-trunk execution: io_callback bridge into "
+                         "the host worker pools, or compiled single-grid "
+                         "Pallas projections with zero host callbacks (the "
+                         "only mode that runs on an accelerator)")
     ap.add_argument("--tuner-cache", default=None,
                     help="JSON path to warm-start/persist the kernel "
                          "tuner's block-shape tables (shared across "
@@ -207,7 +229,7 @@ def main() -> int:
                          "refreshes, capacity/admission events) in a "
                          "bounded ring dumped to PATH; auto-dumps on SLO "
                          "burn or contract trip")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.topology:
         if args.balanced_head:
             raise SystemExit("--topology dispatches the whole trunk; "
@@ -217,16 +239,25 @@ def main() -> int:
                 "--topology provides the virtual clock (the topology's "
                 "flattened machine); drop --machine")
         args.balanced_trunk = True
-    args.machine = args.machine or "ultra-125h"
+    if args.machine is None:
+        # a topology brings its own virtual clock; otherwise a virtual clock
+        # on a chip would report simulated seconds as latency
+        virtual = args.topology or jax.default_backend() == "cpu"
+        args.machine = "ultra-125h" if virtual else "wall"
     if args.balanced_head and args.balanced_trunk:
         raise SystemExit("--balanced-trunk already includes the head; "
                          "drop --balanced-head")
+    return args
 
-    cfg = get_config(args.arch) if args.preset == "full" else reduced_config(args.arch)
-    if cfg.embed_input:
-        raise SystemExit("use examples/ for stub-frontend archs")
-    params = init_params(cfg, jax.random.key(0))
-    max_seq = args.prompt_len + args.steps + 8
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    dev = device_info()
+    print(f"[serve] device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
+    print(f"[serve] compile cache: {enable_compile_cache()}")
+
+    cfg, params, max_seq = build_model(args)
     slot_counts = replica_slot_counts(args.batch, args.replicas)
 
     # observability: install the tracer / flight recorder before any mode
@@ -272,6 +303,99 @@ def main() -> int:
             print(f"[serve] wrote metrics to {args.metrics}")
 
 
+def model_config(args):
+    """The preset's config, cut to ``--layers``."""
+    cfg = (get_config(args.arch) if args.preset == "full"
+           else reduced_config(args.arch))
+    if cfg.embed_input:
+        raise SystemExit("use examples/ for stub-frontend archs")
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    return cfg
+
+
+def build_model(args) -> tuple:
+    """``(cfg, params, max_seq)`` as the options say, with random weights
+    made from ``--seed``."""
+    cfg = model_config(args)
+    params = init_params(cfg, jax.random.key(args.seed))
+    max_seq = args.max_seq or args.prompt_len + args.steps + 8
+    return cfg, params, max_seq
+
+
+def make_requests(args, cfg) -> list:
+    """The seeded open-loop traffic the options describe."""
+    return poisson_requests(
+        args.requests, rate=args.rate, vocab_size=cfg.vocab_size,
+        prompt_len=args.prompt_len, max_new_tokens=args.steps,
+        seed=args.seed)
+
+
+def build_replicas(args, cfg, params, max_seq, slot_counts, *, tuner=None,
+                   sampler=None) -> tuple:
+    """One :class:`ContinuousBatchingEngine` per entry of ``slot_counts``,
+    wired as the options say (clock, balanced head or trunk, dispatcher);
+    returns ``(engines, kernel dispatchers)``.  With several devices,
+    replica ``i`` is committed to device ``i % n_devices`` (params, slot
+    cache and step inputs); ``sampler`` is the engines' logits hook."""
+    devices = jax.devices()
+    chunk = args.prefill_chunk if args.prefill_chunk > 0 else None
+    engines, dispatchers = [], []
+    for i, n_slots in enumerate(slot_counts):
+        p = (params if len(devices) == 1
+             else jax.device_put(params, devices[i % len(devices)]))
+        clock = args.topology or args.machine
+        cost = (None if args.machine == "wall"
+                else HybridPhaseCost(clock, seed=args.seed + i))
+        head, trunk = None, None
+        if args.balanced_head or args.balanced_trunk:
+            if args.topology:
+                disp = TopologyDispatcher(args.topology,
+                                          seed=args.seed + i, execute=True,
+                                          keep_stats=False, tuner=tuner)
+            elif args.machine == "wall":
+                disp = HybridKernelDispatcher.threaded(4, keep_stats=False,
+                                                       tuner=tuner)
+            else:
+                disp = HybridKernelDispatcher.virtual(
+                    args.machine, seed=args.seed + i, execute=True,
+                    keep_stats=False, tuner=tuner)
+            dispatchers.append(disp)
+            if args.balanced_trunk:
+                trunk = BalancedTrunk.from_params(cfg, p, disp,
+                                                  quant=args.trunk_quant,
+                                                  mode=args.trunk_mode)
+            else:
+                head = balanced_lm_head(cfg, p, disp)
+        engines.append(ContinuousBatchingEngine(
+            cfg, p, max_slots=n_slots, max_seq=max_seq,
+            prefill_chunk=chunk, sampler=sampler, cost_model=cost,
+            balanced_head=head, balanced_trunk=trunk))
+    return engines, dispatchers
+
+
+def serve_requests(args, engines, requests, *, table=None) -> tuple:
+    """Serve ``requests`` open loop across ``engines`` behind one
+    :class:`InflightDispatcher`; returns ``(dispatcher, requests routed per
+    replica, LatencyReport)``."""
+    disp = InflightDispatcher(engines, table=table)
+    routed = np.zeros(len(engines), dtype=np.int64)
+    t_wall = time.perf_counter()
+    for r in requests:
+        # Let in-flight work progress up to this arrival so per-phase
+        # throughput feedback from earlier requests steers the routing of
+        # later ones (open loop: arrivals never wait on service).
+        while disp.has_work and disp.now < r.arrival_time:
+            disp.step()
+        i, _ = disp.submit(r)
+        routed[i] += 1
+    disp.run_until_idle()
+    report = LatencyReport.from_requests(
+        requests, clock="virtual" if args.machine != "wall" else "wall",
+        wall_duration=time.perf_counter() - t_wall)
+    return disp, routed, report
+
+
 def run_mode(args, cfg, params, max_seq, slot_counts, registry=None) -> int:
     """Dispatch to the selected serving mode (fleet / legacy / default)."""
     if args.fleet:
@@ -295,72 +419,26 @@ def run_mode(args, cfg, params, max_seq, slot_counts, registry=None) -> int:
         print(f"[serve] generated shape={out.shape}")
         return 0
 
-    chunk = args.prefill_chunk if args.prefill_chunk > 0 else None
-    engines, dispatchers = [], []
     # One kernel tuner shared by every replica dispatcher so a single
     # --tuner-cache file accumulates all block-shape measurements.
     tuner = KernelTuner()
     tuner_store = TunerStore(args.tuner_cache) if args.tuner_cache else None
     if tuner_store is not None and tuner_store.load_into(tuner):
         print(f"[serve] warm-started kernel tuner from {args.tuner_cache}")
-    for i, n_slots in enumerate(slot_counts):
-        clock = args.topology or args.machine
-        cost = (None if args.machine == "wall"
-                else HybridPhaseCost(clock, seed=args.seed + i))
-        head, trunk = None, None
-        if args.balanced_head or args.balanced_trunk:
-            if args.topology:
-                disp = TopologyDispatcher(args.topology,
-                                          seed=args.seed + i, execute=True,
-                                          keep_stats=False, tuner=tuner)
-            elif args.machine == "wall":
-                disp = HybridKernelDispatcher.threaded(4, keep_stats=False,
-                                                       tuner=tuner)
-            else:
-                disp = HybridKernelDispatcher.virtual(
-                    args.machine, seed=args.seed + i, execute=True,
-                    keep_stats=False, tuner=tuner)
-            dispatchers.append(disp)
-            if args.balanced_trunk:
-                trunk = BalancedTrunk.from_params(cfg, params, disp,
-                                                  quant=args.trunk_quant)
-            else:
-                head = balanced_lm_head(cfg, params, disp)
-        engines.append(ContinuousBatchingEngine(
-            cfg, params, max_slots=n_slots, max_seq=max_seq,
-            prefill_chunk=chunk, cost_model=cost, balanced_head=head,
-            balanced_trunk=trunk))
+    engines, dispatchers = build_replicas(args, cfg, params, max_seq,
+                                          slot_counts, tuner=tuner)
 
     table = RatioTable(args.replicas, alpha=0.3)
     store = RatioStore(args.ratios) if args.ratios else None
     if store is not None and store.load_into(table):
         print(f"[serve] warm-started replica ratios from {args.ratios}")
-    disp = InflightDispatcher(engines, table=table)
-
-    requests = poisson_requests(
-        args.requests, rate=args.rate, vocab_size=cfg.vocab_size,
-        prompt_len=args.prompt_len, max_new_tokens=args.steps,
-        seed=args.seed)
-    routed = np.zeros(args.replicas, dtype=np.int64)
-    t_wall = time.perf_counter()
-    for r in requests:
-        # Let in-flight work progress up to this arrival so per-phase
-        # throughput feedback from earlier requests steers the routing of
-        # later ones (open loop: arrivals never wait on service).
-        while disp.has_work and disp.now < r.arrival_time:
-            disp.step()
-        i, _ = disp.submit(r)
-        routed[i] += 1
-    disp.run_until_idle()
-
-    clock = "virtual" if args.machine != "wall" else "wall"
-    report = LatencyReport.from_requests(
-        requests, clock=clock,
-        wall_duration=time.perf_counter() - t_wall)
+    requests = make_requests(args, cfg)
+    disp, routed, report = serve_requests(args, engines, requests,
+                                          table=table)
     if registry is not None:
         report.publish(registry)
     print(f"[serve] {args.replicas} replica(s), slots={slot_counts}, "
-          f"routed={routed.tolist()} ({clock} clock)")
+          f"routed={routed.tolist()} ({report.clock} clock)")
     for line in report.lines():
         print(line)
     print(f"[serve] replica prefill ratios: "

@@ -32,6 +32,7 @@ from repro.checkpoint import latest_step, restore, save
 from repro.configs import get_config, reduced_config
 from repro.runtime import RatioStore, RatioTable
 from repro.data import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.training import AdamWConfig, init_opt_state, make_train_step
 
@@ -41,7 +42,7 @@ def build_mesh_if_useful():
     if n < 2:
         return None
     model = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def main() -> int:

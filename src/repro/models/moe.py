@@ -117,7 +117,6 @@ def moe_fwd(
     """
     from repro.sharding.specs import current_mesh, data_axes
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     m = cfg.moe
     b, s, d = x.shape
@@ -167,14 +166,14 @@ def moe_fwd(
             y_l = _combine(out, dest, st, swk, t_l, x.dtype)
             return y_l, counts[None, :]
 
-        y, counts_g = shard_map(
+        y, counts_g = jax.shard_map(
             moe_local,
             mesh=mesh,
             in_specs=(P(dp, None), P(dp, None),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=(P(dp, None), P(dp, None)),
-            check_rep=False,
+            check_vma=False,
         )(xf, probs, p["wg"], p["wi"], p["wo"])
         counts = counts_g.sum(0)
         dropped = 1.0 - jnp.minimum(counts, c).sum() / jnp.maximum(
@@ -188,7 +187,7 @@ def moe_fwd(
             buf, dest, st, swk, counts = _dispatch(cfg, xf_l, probs_l, c)
             return buf, dest, st, swk, counts[None, :]
 
-        buf, dest, st, swk, counts_g = shard_map(
+        buf, dest, st, swk, counts_g = jax.shard_map(
             dispatch_local,
             mesh=mesh,
             in_specs=(P(dp, None), P(dp, None)),
@@ -200,7 +199,7 @@ def moe_fwd(
         def combine_local(out_buf_l, dest_l, st_l, swk_l):
             return _combine(out_buf_l, dest_l, st_l, swk_l, t_l, x.dtype)
 
-        y = shard_map(
+        y = jax.shard_map(
             combine_local,
             mesh=mesh,
             in_specs=(P(None, dp, None), P(dp), P(dp), P(dp)),

@@ -57,19 +57,25 @@ def _init_layer(cfg: ModelConfig, key, mixer: str, ffn: str) -> dict:
     return p
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _init_stack(cfg: ModelConfig, keys, mixer: str, ffn: str) -> dict:
+    """One period position's parameters for every repeat (one key each),
+    stacked on a leading axis.  Built as one program, so the stack is
+    written in place: stacking per-repeat trees would hold the trunk twice,
+    which at published widths does not fit one chip."""
+    return jax.vmap(lambda k: _init_layer(cfg, k, mixer, ffn))(keys)
+
+
 def init_params(cfg: ModelConfig, key) -> dict:
     """Returns {"embed": ..., "period": [stacked per-position params],
     "final_norm": ...}."""
     period = cfg.period()
     n_rep = cfg.n_periods
     keys = jax.random.split(key, n_rep * len(period) + 2)
-    stacked = []
-    for j, (mixer, ffn) in enumerate(period):
-        per_rep = [
-            _init_layer(cfg, keys[i * len(period) + j], mixer, ffn)
-            for i in range(n_rep)
-        ]
-        stacked.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_rep))
+    # repeat i of position j draws from keys[i * len(period) + j]
+    stacked = [_init_stack(cfg, keys[j:n_rep * len(period):len(period)],
+                           mixer, ffn)
+               for j, (mixer, ffn) in enumerate(period)]
     return {
         "embed": init_embedding(cfg, keys[-2]),
         "period": stacked,

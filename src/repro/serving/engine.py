@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import events as _ev
+from repro.device import committed_device
 from repro.models import forward, init_state
 from repro.models.attention import KVCache
 from repro.runtime import (
@@ -164,6 +165,104 @@ class ServeEngine:
         )
 
 
+def step_programs(cfg: ModelConfig, *, trunk=None, apply_head: bool = True,
+                  donate_state: bool = True):
+    """The engine's three step programs for ``cfg``: ``(prefill,
+    prefill_lanes, decode)``.
+
+    They are jitted unless the balanced trunk runs eagerly; decode donates
+    its state argument.  With a compiled trunk every program takes the
+    device offset snapshot as its last argument and returns the traced cost
+    tape as an extra output.  Module-level so that the programs can be
+    lowered for a chip without building an engine (and its state)."""
+    # Tracing-disallowed fallback: a trunk built with jit_bridge=False
+    # runs its shard dispatches eagerly, so the step functions must
+    # not be jitted (the io_callback bridge would otherwise trace).
+    use_jit = trunk is None or trunk.jit_bridge
+    # Compiled trunk: the step functions take the device offset
+    # snapshot as an extra argument, apply the balanced head in-graph,
+    # and return the traced cost tape as an extra output — zero host
+    # callbacks inside the step; ratio feedback + offset refresh run
+    # between steps (see repro.kernels.compiled).
+    compiled = trunk is not None and getattr(trunk, "mode", None) == "compiled"
+
+    donate = (2,) if donate_state and use_jit else ()
+
+    if compiled:
+        def _head_in_graph(logits, phase, offsets):
+            if trunk.head is None:
+                return logits
+            return trunk.apply_head(logits, isa=PHASE_ISA[phase],
+                                    offsets=offsets)
+
+        def _prefill(params, tokens, state, offset, offsets):
+            tape = trunk.compiled_tape_begin()
+            out = forward(cfg, params, tokens, state=state,
+                          pos_offset=offset, logits_mode="last",
+                          apply_head=apply_head, trunk=trunk,
+                          trunk_isa=PHASE_ISA[PREFILL],
+                          trunk_offsets=offsets)
+            logits = _head_in_graph(out.logits[:, -1, :], PREFILL,
+                                    offsets)
+            return logits, out.state, trunk.compiled_tape_end(tape)
+
+        def _decode(params, tok, state, pos, offsets):
+            tape = trunk.compiled_tape_begin()
+            out = forward(cfg, params, tok, state=state, pos_offset=pos,
+                          apply_head=apply_head, trunk=trunk,
+                          trunk_isa=PHASE_ISA[DECODE],
+                          trunk_offsets=offsets)
+            logits = _head_in_graph(out.logits[:, -1, :], DECODE,
+                                    offsets)
+            return logits, out.state, trunk.compiled_tape_end(tape)
+
+        def _prefill_lanes_fn(params, tokens, states, offsets, snap):
+            tape = trunk.compiled_tape_begin()
+            stacked = _stack_lane_states(states)
+            out = forward(cfg, params, tokens, state=stacked,
+                          pos_offset=offsets, logits_mode="last",
+                          apply_head=apply_head, trunk=trunk,
+                          trunk_isa=PHASE_ISA[PREFILL],
+                          trunk_offsets=snap)
+            rows = [_slice_lane_state(out.state, i)
+                    for i in range(len(states))]
+            logits = _head_in_graph(out.logits[:, -1, :], PREFILL, snap)
+            return logits, rows, trunk.compiled_tape_end(tape)
+    else:
+        def _prefill(params, tokens, state, offset):
+            out = forward(cfg, params, tokens, state=state,
+                          pos_offset=offset, logits_mode="last",
+                          apply_head=apply_head,
+                          trunk=trunk, trunk_isa=PHASE_ISA[PREFILL])
+            return out.logits[:, -1, :], out.state
+
+        def _decode(params, tok, state, pos):
+            out = forward(cfg, params, tok, state=state, pos_offset=pos,
+                          apply_head=apply_head,
+                          trunk=trunk, trunk_isa=PHASE_ISA[DECODE])
+            return out.logits[:, -1, :], out.state
+
+        def _prefill_lanes_fn(params, tokens, states, offsets):
+            # One batched trunk call over all active lanes: per-row
+            # cache offsets (each lane appends at its own position),
+            # then the rows split back into batch-1 partial states.
+            stacked = _stack_lane_states(states)
+            out = forward(cfg, params, tokens, state=stacked,
+                          pos_offset=offsets, logits_mode="last",
+                          apply_head=apply_head, trunk=trunk,
+                          trunk_isa=PHASE_ISA[PREFILL])
+            rows = [_slice_lane_state(out.state, i)
+                    for i in range(len(states))]
+            return out.logits[:, -1, :], rows
+
+    if use_jit:
+        _prefill = jax.jit(_prefill)
+        _prefill_lanes_fn = jax.jit(_prefill_lanes_fn)
+        _decode = functools.partial(jax.jit, donate_argnums=donate)(_decode)
+
+    return _prefill, _prefill_lanes_fn, _decode
+
+
 class ContinuousBatchingEngine:
     """Request-level engine: persistent decode batch + interleaved prefill.
 
@@ -226,7 +325,15 @@ class ContinuousBatchingEngine:
         apply_head = (balanced_head is None
                       and (balanced_trunk is None
                            or balanced_trunk.head is None))
-        self.manager = SlotCacheManager(cfg, max_slots, max_seq)
+        # a replica committed to one device keeps its state and step inputs
+        # there (None: JAX's default device)
+        self.device = committed_device(params)
+        with jax.default_device(self.device):
+            self.manager = SlotCacheManager(cfg, max_slots, max_seq)
+            # One prefill lane -> one partial state.  The fresh template is
+            # allocated once and reused for every admission (_prefill never
+            # donates its state argument, so the template stays intact).
+            self._fresh_prefill_state = init_state(cfg, 1, max_seq)
         self.scheduler = IterationScheduler(prefill_chunk,
                                             prefill_lanes=prefill_lanes)
         # soft concurrency cap (<= max_slots): admission headroom only, so
@@ -236,110 +343,22 @@ class ContinuousBatchingEngine:
         self.now = 0.0
         self.finished: List[Request] = []
         self._running: List[Request] = []
-        # One prefill lane -> one partial state.  The fresh template is
-        # allocated once and reused for every admission (_prefill never
-        # donates its state argument, so the template stays intact).
-        self._fresh_prefill_state = init_state(cfg, 1, max_seq)
         self._partial = None           # in-flight batch-1 prefill state
         self._partials = {}            # request_id -> state (multi-lane)
         self._next_id = 0
         # (B,) greedy rows by default; a sampler sees (B, V) logits.
         self._pick = sampler or (lambda lg: jnp.argmax(lg, -1))
 
-        trunk = balanced_trunk
-        # Tracing-disallowed fallback: a trunk built with jit_bridge=False
-        # runs its shard dispatches eagerly, so the step functions must
-        # not be jitted (the io_callback bridge would otherwise trace).
-        use_jit = trunk is None or trunk.jit_bridge
-        # Compiled trunk: the step functions take the device offset
-        # snapshot as an extra argument, apply the balanced head in-graph,
-        # and return the traced cost tape as an extra output — zero host
-        # callbacks inside the step; ratio feedback + offset refresh run
-        # between steps (see repro.kernels.compiled).
-        compiled = trunk is not None and getattr(trunk, "mode",
-                                                 None) == "compiled"
+        compiled = (balanced_trunk is not None
+                    and getattr(balanced_trunk, "mode", None) == "compiled")
         self._compiled_trunk = compiled
-
-        donate = (2,) if donate_state and use_jit else ()
-
-        if compiled:
-            def _head_in_graph(logits, phase, offsets):
-                if trunk.head is None:
-                    return logits
-                return trunk.apply_head(logits, isa=PHASE_ISA[phase],
-                                        offsets=offsets)
-
-            def _prefill(params, tokens, state, offset, offsets):
-                tape = trunk.compiled_tape_begin()
-                out = forward(cfg, params, tokens, state=state,
-                              pos_offset=offset, logits_mode="last",
-                              apply_head=apply_head, trunk=trunk,
-                              trunk_isa=PHASE_ISA[PREFILL],
-                              trunk_offsets=offsets)
-                logits = _head_in_graph(out.logits[:, -1, :], PREFILL,
-                                        offsets)
-                return logits, out.state, trunk.compiled_tape_end(tape)
-
-            def _decode(params, tok, state, pos, offsets):
-                tape = trunk.compiled_tape_begin()
-                out = forward(cfg, params, tok, state=state, pos_offset=pos,
-                              apply_head=apply_head, trunk=trunk,
-                              trunk_isa=PHASE_ISA[DECODE],
-                              trunk_offsets=offsets)
-                logits = _head_in_graph(out.logits[:, -1, :], DECODE,
-                                        offsets)
-                return logits, out.state, trunk.compiled_tape_end(tape)
-
-            def _prefill_lanes_fn(params, tokens, states, offsets, snap):
-                tape = trunk.compiled_tape_begin()
-                stacked = _stack_lane_states(states)
-                out = forward(cfg, params, tokens, state=stacked,
-                              pos_offset=offsets, logits_mode="last",
-                              apply_head=apply_head, trunk=trunk,
-                              trunk_isa=PHASE_ISA[PREFILL],
-                              trunk_offsets=snap)
-                rows = [_slice_lane_state(out.state, i)
-                        for i in range(len(states))]
-                logits = _head_in_graph(out.logits[:, -1, :], PREFILL, snap)
-                return logits, rows, trunk.compiled_tape_end(tape)
-        else:
-            def _prefill(params, tokens, state, offset):
-                out = forward(cfg, params, tokens, state=state,
-                              pos_offset=offset, logits_mode="last",
-                              apply_head=apply_head,
-                              trunk=trunk, trunk_isa=PHASE_ISA[PREFILL])
-                return out.logits[:, -1, :], out.state
-
-            def _decode(params, tok, state, pos):
-                out = forward(cfg, params, tok, state=state, pos_offset=pos,
-                              apply_head=apply_head,
-                              trunk=trunk, trunk_isa=PHASE_ISA[DECODE])
-                return out.logits[:, -1, :], out.state
-
-            def _prefill_lanes_fn(params, tokens, states, offsets):
-                # One batched trunk call over all active lanes: per-row
-                # cache offsets (each lane appends at its own position),
-                # then the rows split back into batch-1 partial states.
-                stacked = _stack_lane_states(states)
-                out = forward(cfg, params, tokens, state=stacked,
-                              pos_offset=offsets, logits_mode="last",
-                              apply_head=apply_head, trunk=trunk,
-                              trunk_isa=PHASE_ISA[PREFILL])
-                rows = [_slice_lane_state(out.state, i)
-                        for i in range(len(states))]
-                return out.logits[:, -1, :], rows
-
-        if use_jit:
-            _prefill = jax.jit(_prefill)
-            _prefill_lanes_fn = jax.jit(_prefill_lanes_fn)
-            _decode = functools.partial(jax.jit, donate_argnums=donate)(_decode)
-
-        self._prefill = _prefill
-        self._prefill_lanes = _prefill_lanes_fn
-        self._decode = _decode
+        self._prefill, self._prefill_lanes, self._decode = step_programs(
+            cfg, trunk=balanced_trunk, apply_head=apply_head,
+            donate_state=donate_state)
         # Initial offset snapshot (compiled mode): planned from whatever
         # the ratio tables currently hold, refreshed after every step.
-        self._offsets = trunk.compiled_refresh() if compiled else None
+        self._offsets = (balanced_trunk.compiled_refresh() if compiled
+                         else None)
 
     @staticmethod
     def _adopt_topology(trunk, topology):
@@ -501,6 +520,10 @@ class ContinuousBatchingEngine:
     def step(self) -> IterationStats:
         """Run one scheduler iteration; returns what it did (the per-phase
         feedback record)."""
+        with jax.default_device(self.device):
+            return self._step()
+
+    def _step(self) -> IterationStats:
         st = IterationStats()
         man, sched = self.manager, self.scheduler
 

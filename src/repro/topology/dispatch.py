@@ -85,7 +85,8 @@ class TopologyDispatcher:
                  execute: bool = False, alpha: float = 0.3, seed: int = 0,
                  table: Optional[RatioTable] = None,
                  tuner: Optional[KernelTuner] = None,
-                 sink: Optional[StatsSink] = None, interpret: bool = True,
+                 sink: Optional[StatsSink] = None,
+                 interpret: Optional[bool] = None,
                  keep_stats: bool = True):
         if isinstance(topology, str):
             topology = make_topology(topology, seed=seed)

@@ -33,6 +33,20 @@ def test_q4_roundtrip_exact_codes():
     np.testing.assert_array_equal(np.asarray(qw.packed), np.asarray(qw2.packed))
 
 
+def test_f16_bits_to_f32_every_pattern():
+    """The kernel rebuilds f16 scales from their raw bits with integer ops:
+    exact for every normal, subnormal, zero and infinity; NaN stays NaN."""
+    from repro.kernels.q4_matmul import f16_bits_to_f32
+
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    want = bits.view(np.float16).astype(np.float32)
+    got = np.asarray(f16_bits_to_f32(jnp.asarray(bits.astype(np.int32))))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan], want[~nan])
+    np.testing.assert_array_equal(np.signbit(got[~nan]), np.signbit(want[~nan]))
+
+
 def test_q4_quant_error_bounded():
     w = RNG.normal(size=(16, 128)).astype(np.float32)
     qw = quantize_q4_0(jnp.asarray(w))
