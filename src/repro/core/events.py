@@ -57,6 +57,9 @@ __all__ = [
     "emit_instant",
     "push_scope",
     "pop_scope",
+    "SPANS",
+    "install_spans",
+    "span",
     "record",
 ]
 
@@ -246,6 +249,46 @@ def pop_scope() -> None:
     fn = getattr(t, "pop_scope", None)
     if fn is not None:
         fn()
+
+
+# -------------------------------------------------------------- host spans --
+# Wall-clock spans inside the program (the engine step and its parts).
+# ``span`` always opens a ``jax.profiler.TraceAnnotation``: it costs about a
+# microsecond unless the profiler records, and then lands on the host line
+# of the same trace as the device's programs, on their clock.  An installed
+# host-span sink (:class:`repro.obs.HostSpans`) also keeps every span in
+# memory, on the host clock.  The virtual-clock tracer never receives these
+# spans: its traces stay deterministic.
+SPANS = None
+
+
+def _annotation(name: str, **args):
+    # jax is imported on the first span, so that this shim stays importable
+    # without it; the binding then points at the profiler's class directly
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation(name, **args)
+
+
+def install_spans(sink):
+    """Install a host-span sink (anything with ``span(name, args, open)``
+    returning a context manager), or ``None``; returns the previous one."""
+    global SPANS
+    prev = SPANS
+    SPANS = sink
+    return prev
+
+
+def span(name: str, **args):
+    """A context manager timing one host span ``name``; ``args`` are ints
+    (no string is built on the hot path).  With no sink installed the cost
+    is one global load and the profiler annotation."""
+    sink = SPANS
+    if sink is None:
+        return _annotation(name, **args)
+    return sink.span(name, args, _annotation)
 
 
 # ---------------------------------------------------------------- recorder --

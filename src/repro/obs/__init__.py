@@ -11,8 +11,14 @@ shim so instrumented call sites stay a single global load when disabled:
 * :class:`FlightRecorder` (:mod:`repro.obs.recorder`) — a bounded ring of
   recent balancer decisions dumped to disk when an SLO burn or an invariant
   contract (IV00x) trips.
+
+Beside them, on the host clock and outside the virtual set,
+:class:`HostSpans` (:mod:`repro.obs.host`) keeps the wall-clock spans that
+:func:`repro.core.events.span` opens inside the engine step, and
+:class:`CompileCounter` counts the programs JAX lowers.
 """
 
+from repro.obs.host import CompileCounter, HostSpan, HostSpans
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                TPOT_BUCKETS, TTFT_BUCKETS, lint_exposition)
 from repro.obs.recorder import DecisionRecord, FlightRecorder
@@ -30,4 +36,7 @@ __all__ = [
     "lint_exposition",
     "FlightRecorder",
     "DecisionRecord",
+    "HostSpans",
+    "HostSpan",
+    "CompileCounter",
 ]

@@ -188,6 +188,13 @@ class InflightDispatcher:
         ratios keep learning even when replicas never work in the same
         iteration.  Deactivated replicas are not stepped and contribute
         empty stats (units 0 -> masked out of the update)."""
+        with _ev.span("dispatch.step"):
+            stats = self._step_replicas()
+            with _ev.span("dispatch.feedback"):
+                self._feedback(stats)
+        return stats
+
+    def _step_replicas(self) -> List[IterationStats]:
         tracing = _ev.TRACER is not None
         stats = []
         for i, e in enumerate(self.engines):
@@ -204,6 +211,9 @@ class InflightDispatcher:
                     _ev.pop_scope()
             else:
                 stats.append(e.step())
+        return stats
+
+    def _feedback(self, stats: List[IterationStats]) -> None:
         for phase, units, times in (
             (PREFILL,
              np.array([s.prefill_tokens for s in stats], dtype=np.int64),
@@ -229,7 +239,6 @@ class InflightDispatcher:
                     Plan(counts=acc_u.copy(), key=phase), acc_t.copy())
                 acc_u[:] = 0
                 acc_t[:] = 0.0
-        return stats
 
     def run_until_idle(self, max_steps: Optional[int] = None
                        ) -> List[List[IterationStats]]:

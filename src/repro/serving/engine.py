@@ -420,6 +420,8 @@ class ContinuousBatchingEngine:
                 f"max_seq={self.max_seq}")
         request.request_id = self._next_id
         self._next_id += 1
+        if request.host_queued is None:
+            request.host_queued = time.perf_counter()
         self.scheduler.submit(request)
         return request.request_id
 
@@ -520,31 +522,117 @@ class ContinuousBatchingEngine:
     def step(self) -> IterationStats:
         """Run one scheduler iteration; returns what it did (the per-phase
         feedback record)."""
-        with jax.default_device(self.device):
+        with _ev.span("engine.step"), jax.default_device(self.device):
             return self._step()
 
     def _step(self) -> IterationStats:
+        # Host spans (repro.core.events.span) mark each part of the step on
+        # the wall clock, on both paths; with a cost model the engine also
+        # emits its prefill/decode spans on the virtual clock (emit_span),
+        # under the same names.
         st = IterationStats()
         man, sched = self.manager, self.scheduler
 
-        # Idle fast-forward: nothing to run until the next arrival.
-        if (not self._running and not sched.lanes
-                and sched.waiting and not sched.n_waiting(self.now)):
-            self.now = max(self.now, sched.waiting[0].arrival_time)
-
-        # admission headroom: free slots, clamped by the soft slot budget
-        # (a capacity event may have shrunk the sustainable concurrency)
-        budget_free = max(0, min(man.n_free,
-                                 self.slot_budget - man.n_active))
-        chunks = sched.next_prefill(self.now, budget_free)
+        with _ev.span("engine.schedule"):
+            # Idle fast-forward: nothing to run until the next arrival.
+            if (not self._running and not sched.lanes
+                    and sched.waiting and not sched.n_waiting(self.now)):
+                self.now = max(self.now, sched.waiting[0].arrival_time)
+            # admission headroom: free slots, clamped by the soft slot
+            # budget (a capacity event may have shrunk the sustainable
+            # concurrency)
+            budget_free = max(0, min(man.n_free,
+                                     self.slot_budget - man.n_active))
+            chunks = sched.next_prefill(self.now, budget_free)
+            for c in chunks:
+                if c.request.slot is None:  # newly admitted
+                    self._admit(c.request)
         if chunks and self.prefill_lanes == 1:
-            chunk = chunks[0]
-            req = chunk.request
-            if req.slot is None:  # newly admitted: reserve the slot now
-                req.slot = man.allocate()
-                req.state = RequestState.PREFILL
-                req.admit_time = self.now
-                self._partial = self._fresh_prefill_state
+            self._step_prefill(chunks[0], st)
+        elif chunks:
+            self._step_prefill_lanes(chunks, st)
+
+        if self._running:
+            with _ev.span("engine.decode", rows=len(self._running)):
+                tok = jnp.asarray(man.last_token[:, None])
+                pos = jnp.asarray(man.pos)
+                t0 = time.perf_counter()
+                if self._compiled_trunk:
+                    logits, man.state, recs = self._decode(
+                        self.params, tok, man.state, pos, self._offsets)
+                else:
+                    logits, man.state = self._decode(self.params, tok,
+                                                     man.state, pos)
+                with _ev.span("engine.decode.sync"):
+                    next_tok = np.asarray(
+                        self._pick(self._head(logits, DECODE))).reshape(-1)
+            if self.cost_model is None:
+                dt = time.perf_counter() - t0
+            else:
+                dt = self.cost_model.decode_seconds(
+                    len(self._running), ctx=int(man.pos.max()))
+            if self._compiled_trunk:
+                self._feedback(recs)
+            if self.cost_model is not None:
+                _ev.emit_span(
+                    "engine", "engine.decode", self.now, dt, cat="engine",
+                    args=lambda: {"batch": len(self._running)})
+            self.now += dt
+            st.decode_tokens = len(self._running)
+            st.decode_seconds = dt
+            with _ev.span("engine.finish"):
+                for req in list(self._running):
+                    t = int(next_tok[req.slot])
+                    req.generated.append(t)
+                    man.last_token[req.slot] = t
+                    man.pos[req.slot] += 1
+                    self._maybe_finish(req, t, st)
+
+        st.n_running = len(self._running)
+        st.n_waiting = self.scheduler.n_waiting()
+        st.now = self.now
+        if self.cost_model is not None:
+            _ev.emit_counter("queue", self.now,
+                             lambda: {"depth": float(self.queue_depth)})
+        return st
+
+    def _admit(self, req: Request) -> None:
+        """Reserve a decode slot for a request the prefill lane just took,
+        and give it a fresh batch-1 prefill state."""
+        req.slot = self.manager.allocate()
+        req.state = RequestState.PREFILL
+        req.admit_time = self.now
+        req.host_admitted = time.perf_counter()
+        if self.prefill_lanes == 1:
+            self._partial = self._fresh_prefill_state
+        else:
+            self._partials[req.request_id] = self._fresh_prefill_state
+
+    def _feedback(self, recs) -> None:
+        """Compiled trunk, between steps: replay the step's cost tape into
+        the ratio tables and refresh the offset snapshot."""
+        with _ev.span("engine.feedback"):
+            self._offsets = self.balanced_trunk.compiled_feedback(
+                jax.device_get(recs))
+
+    def _first_token(self, req: Request, tok: int, state, st) -> None:
+        """A request's prefill ended with ``tok``: it joins the decode
+        batch in its slot."""
+        req.generated.append(tok)
+        req.first_token_time = self.now
+        req.host_first_token = time.perf_counter()
+        self.manager.adopt(req.slot, state, req.prompt_len, tok)
+        req.state = RequestState.RUNNING
+        self._running.append(req)
+        st.admitted.append(req.request_id)
+        self._maybe_finish(req, tok, st)
+
+    def _step_prefill(self, chunk, st: IterationStats) -> None:
+        """One prompt piece of the single prefill lane, on its detached
+        batch-1 state; the last piece samples the first token."""
+        req = chunk.request
+        with _ev.span("engine.prefill", tokens=chunk.length,
+                      start=chunk.start):
             tokens = jnp.asarray(
                 req.prompt[chunk.start:chunk.start + chunk.length][None, :])
             t0 = time.perf_counter()
@@ -556,89 +644,38 @@ class ContinuousBatchingEngine:
                 logits, small = self._prefill(
                     self.params, tokens, self._partial,
                     jnp.asarray(chunk.start, jnp.int32))
-            tok = None
-            if chunk.is_last:
-                # head + sampling inside the timed window, matching the
-                # decode lane — with a balanced head the host-side GEMV is
-                # part of the step, so TTFT must include it
-                tok = int(np.asarray(
-                    self._pick(self._head(logits, PREFILL))).reshape(-1)[0])
-            if self.cost_model is None:
-                logits.block_until_ready()
-                dt = time.perf_counter() - t0
-            else:
-                dt = self.cost_model.prefill_seconds(
-                    chunk.length, ctx=chunk.start + chunk.length)
-            if self._compiled_trunk:
-                # Between-step feedback: replay the step's cost tape into
-                # the ratio tables and refresh the offset snapshot.
-                self._offsets = self.balanced_trunk.compiled_feedback(
-                    jax.device_get(recs))
-            req.prefill_done += chunk.length
-            sched.prefill_advanced(chunk)
-            if self.cost_model is not None:
-                # span on the engine's virtual clock (wall-timed engines
-                # stay untraced: their timestamps are not deterministic)
-                _ev.emit_span("engine", PREFILL, self.now, dt, cat="engine",
-                              args=lambda: {"tokens": int(chunk.length)})
-            self.now += dt
-            st.prefill_tokens = chunk.length
-            st.prefill_seconds = dt
-            if chunk.is_last:
-                self._partial = None
-                req.generated.append(tok)
-                req.first_token_time = self.now
-                man.adopt(req.slot, small, req.prompt_len, tok)
-                req.state = RequestState.RUNNING
-                self._running.append(req)
-                st.admitted.append(req.request_id)
-                self._maybe_finish(req, tok, st)
-            else:
-                self._partial = small
-        elif chunks:
-            self._step_prefill_lanes(chunks, st)
-
-        if self._running:
-            tok = jnp.asarray(man.last_token[:, None])
-            pos = jnp.asarray(man.pos)
-            t0 = time.perf_counter()
-            if self._compiled_trunk:
-                logits, man.state, recs = self._decode(
-                    self.params, tok, man.state, pos, self._offsets)
-            else:
-                logits, man.state = self._decode(self.params, tok,
-                                                 man.state, pos)
-            next_tok = np.asarray(
-                self._pick(self._head(logits, DECODE))).reshape(-1)
-            if self.cost_model is None:
-                dt = time.perf_counter() - t0
-            else:
-                dt = self.cost_model.decode_seconds(
-                    len(self._running), ctx=int(man.pos.max()))
-            if self._compiled_trunk:
-                self._offsets = self.balanced_trunk.compiled_feedback(
-                    jax.device_get(recs))
-            if self.cost_model is not None:
-                _ev.emit_span(
-                    "engine", DECODE, self.now, dt, cat="engine",
-                    args=lambda: {"batch": len(self._running)})
-            self.now += dt
-            st.decode_tokens = len(self._running)
-            st.decode_seconds = dt
-            for req in list(self._running):
-                t = int(next_tok[req.slot])
-                req.generated.append(t)
-                man.last_token[req.slot] = t
-                man.pos[req.slot] += 1
-                self._maybe_finish(req, t, st)
-
-        st.n_running = len(self._running)
-        st.n_waiting = self.scheduler.n_waiting()
-        st.now = self.now
+            with _ev.span("engine.prefill.sync"):
+                tok = None
+                if chunk.is_last:
+                    # head + sampling inside the timed window, matching the
+                    # decode lane — with a balanced head the host-side GEMV
+                    # is part of the step, so TTFT must include it
+                    tok = int(np.asarray(
+                        self._pick(self._head(logits, PREFILL))).reshape(-1)[0])
+                if self.cost_model is None:
+                    logits.block_until_ready()
+        if self.cost_model is None:
+            dt = time.perf_counter() - t0
+        else:
+            dt = self.cost_model.prefill_seconds(
+                chunk.length, ctx=chunk.start + chunk.length)
+        if self._compiled_trunk:
+            self._feedback(recs)
+        req.prefill_done += chunk.length
+        req.prefill_pieces += 1
+        self.scheduler.prefill_advanced(chunk)
         if self.cost_model is not None:
-            _ev.emit_counter("queue", self.now,
-                             lambda: {"depth": float(self.queue_depth)})
-        return st
+            _ev.emit_span("engine", "engine.prefill", self.now, dt,
+                          cat="engine",
+                          args=lambda: {"tokens": int(chunk.length)})
+        self.now += dt
+        st.prefill_tokens = chunk.length
+        st.prefill_seconds = dt
+        if chunk.is_last:
+            self._partial = None
+            self._first_token(req, tok, small, st)
+        else:
+            self._partial = small
 
     def _step_prefill_lanes(self, chunks, st: IterationStats) -> None:
         """Multi-lane prefill: all active lanes advance by one shared-length
@@ -647,35 +684,31 @@ class ContinuousBatchingEngine:
         what the balanced per-core split wants to see.  Token-identical to
         the batch-1 path: rows of a matmul are independent and each lane's
         cache rows are its own."""
-        man, sched = self.manager, self.scheduler
-        for c in chunks:
-            req = c.request
-            if req.slot is None:  # newly admitted: reserve the slot now
-                req.slot = man.allocate()
-                req.state = RequestState.PREFILL
-                req.admit_time = self.now
-                self._partials[req.request_id] = self._fresh_prefill_state
         length = chunks[0].length
-        tokens = jnp.asarray(np.stack(
-            [np.asarray(c.request.prompt[c.start:c.start + length])
-             for c in chunks]))
-        offsets = jnp.asarray(
-            np.array([c.start for c in chunks], dtype=np.int32))
-        states = [self._partials[c.request.request_id] for c in chunks]
-        t0 = time.perf_counter()
-        if self._compiled_trunk:
-            logits, rows, recs = self._prefill_lanes(
-                self.params, tokens, states, offsets, self._offsets)
-        else:
-            logits, rows = self._prefill_lanes(self.params, tokens, states,
-                                               offsets)
-        finishing = [i for i, c in enumerate(chunks) if c.is_last]
-        picked = None
-        if finishing:  # head + sampling inside the timed window (TTFT)
-            picked = np.asarray(
-                self._pick(self._head(logits, PREFILL))).reshape(-1)
+        with _ev.span("engine.prefill", tokens=length * len(chunks),
+                      lanes=len(chunks)):
+            tokens = jnp.asarray(np.stack(
+                [np.asarray(c.request.prompt[c.start:c.start + length])
+                 for c in chunks]))
+            offsets = jnp.asarray(
+                np.array([c.start for c in chunks], dtype=np.int32))
+            states = [self._partials[c.request.request_id] for c in chunks]
+            t0 = time.perf_counter()
+            if self._compiled_trunk:
+                logits, rows, recs = self._prefill_lanes(
+                    self.params, tokens, states, offsets, self._offsets)
+            else:
+                logits, rows = self._prefill_lanes(self.params, tokens,
+                                                   states, offsets)
+            with _ev.span("engine.prefill.sync"):
+                picked = None
+                if any(c.is_last for c in chunks):
+                    # head + sampling inside the timed window (TTFT)
+                    picked = np.asarray(
+                        self._pick(self._head(logits, PREFILL))).reshape(-1)
+                if self.cost_model is None:
+                    logits.block_until_ready()
         if self.cost_model is None:
-            logits.block_until_ready()
             dt = time.perf_counter() - t0
         else:
             # one parallel region over all lanes' tokens: the batched call
@@ -684,11 +717,10 @@ class ContinuousBatchingEngine:
                 length * len(chunks),
                 ctx=max(c.start + length for c in chunks))
         if self._compiled_trunk:
-            self._offsets = self.balanced_trunk.compiled_feedback(
-                jax.device_get(recs))
+            self._feedback(recs)
         if self.cost_model is not None:
             _ev.emit_span(
-                "engine", PREFILL, self.now, dt, cat="engine",
+                "engine", "engine.prefill", self.now, dt, cat="engine",
                 args=lambda: {"tokens": int(length * len(chunks)),
                               "lanes": len(chunks)})
         self.now += dt
@@ -697,17 +729,11 @@ class ContinuousBatchingEngine:
         for i, c in enumerate(chunks):
             req = c.request
             req.prefill_done += length
-            sched.prefill_advanced(c)
+            req.prefill_pieces += 1
+            self.scheduler.prefill_advanced(c)
             if c.is_last:
-                tok = int(picked[i])
                 self._partials.pop(req.request_id, None)
-                req.generated.append(tok)
-                req.first_token_time = self.now
-                man.adopt(req.slot, rows[i], req.prompt_len, tok)
-                req.state = RequestState.RUNNING
-                self._running.append(req)
-                st.admitted.append(req.request_id)
-                self._maybe_finish(req, tok, st)
+                self._first_token(req, int(picked[i]), rows[i], st)
             else:
                 self._partials[req.request_id] = rows[i]
 

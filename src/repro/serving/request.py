@@ -8,7 +8,9 @@ continuous-batching engine's lifecycle::
 
 Timestamps are recorded in the engine's clock domain (wall seconds, or
 virtual seconds when a phase cost model drives the clock), so latency
-metrics (TTFT / TPOT) are deterministic under the simulator.
+metrics (TTFT / TPOT) are deterministic under the simulator.  Beside them
+the engine stamps the host clock (``time.perf_counter``) at the same
+boundaries, for callers that time the engine from outside.
 """
 
 from __future__ import annotations
@@ -66,6 +68,12 @@ class Request:
     admit_time: Optional[float] = None   # prefill started
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+
+    # --- host record (time.perf_counter seconds, whatever the engine clock)
+    host_queued: Optional[float] = None       # first submit()
+    host_admitted: Optional[float] = None     # the prefill lane took it
+    host_first_token: Optional[float] = None  # its first token sampled
+    prefill_pieces: int = 0                   # prefill calls its prompt took
 
     def __post_init__(self) -> None:
         self.prompt = np.asarray(self.prompt, dtype=np.int32).reshape(-1)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import events as _ev
 from repro.models import init_slot_state
 from repro.models.attention import KVCache
 
@@ -100,8 +101,9 @@ class SlotCacheManager:
         if n_context + 1 > self.max_seq:
             raise ValueError(
                 f"context {n_context} leaves no room in max_seq {self.max_seq}")
-        self.state = _adopt(self.state, small_state,
-                            jnp.asarray(slot, jnp.int32))
+        with _ev.span("engine.adopt"):
+            self.state = _adopt(self.state, small_state,
+                                jnp.asarray(slot, jnp.int32))
         self.pos[slot] = n_context
         self.last_token[slot] = last_token
 
@@ -109,7 +111,8 @@ class SlotCacheManager:
         """Return a slot to the free list (its cache rows become dead)."""
         if slot in self._free:
             raise ValueError(f"slot {slot} is already free")
-        self.state = _reset_slot(self.state, jnp.asarray(slot, jnp.int32))
+        with _ev.span("engine.release"):
+            self.state = _reset_slot(self.state, jnp.asarray(slot, jnp.int32))
         self.pos[slot] = 0
         self.last_token[slot] = 0
         self._free.append(slot)
