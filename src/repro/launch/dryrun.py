@@ -170,7 +170,9 @@ def build_cell(cfg, shape: ShapeSpec, mesh, n_micro: int = 8):
                       None if use_embeds else tok,
                       embeds=tok if use_embeds else None,
                       state=state, pos_offset=offset, logits_mode="last")
-        return out.logits, out.state
+        # the state rides the layer scan's carry: hold the updated state to
+        # the input's shardings so the carry keeps them through the loop
+        return out.logits, jax.lax.with_sharding_constraint(out.state, s_sh)
 
     return decode, (params, tok_spec, state, offset), (p_sh, t_sh, s_sh, off_sh)
 

@@ -8,8 +8,8 @@ period position are stacked over repeats and the trunk is a single
 lowers as one 8-layer body — HLO size and compile time stay bounded across
 the whole zoo.
 
-States (KV caches / SSM / xLSTM states) follow the same stacking so that
-prefill/decode scan over the same structure.
+States (KV caches / SSM / xLSTM states) follow the same stacking and ride
+in the scan's carry: each layer updates its repeat of the stack in place.
 """
 
 from __future__ import annotations
@@ -42,6 +42,13 @@ MIXER_INIT = {
     "mamba": S.init_mamba,
     "mlstm": X.init_mlstm,
     "slstm": X.init_slstm,
+}
+
+# mixers whose decoding state is a small recurrent state, not a KV cache
+RECURRENT_FWD = {
+    "mamba": S.mamba_fwd,
+    "mlstm": X.mlstm_fwd,
+    "slstm": X.slstm_fwd,
 }
 
 
@@ -145,18 +152,23 @@ class ForwardOut(NamedTuple):
     aux: dict
 
 
-def _apply_layer(cfg, mixer, ffn, p, x, positions, state, capacity,
+def _apply_layer(cfg, mixer, ffn, p, x, positions, state, layer, capacity,
                  proj_attn=None, proj_ffn=None):
+    """One layer.  ``state`` is this period position's state stacked over
+    its repeats (or None); the layer reads repeat ``layer`` of it and
+    returns the stack with that repeat updated: attention appends its new
+    K/V rows in place, the small SSM/xLSTM states replace their entry."""
     h = norm_fwd(cfg, p["norm1"], x)
     if mixer == "attn":
-        mix, new_state = A.attn_fwd(cfg, p["mixer"], h, positions, state,
-                                    proj=proj_attn)
-    elif mixer == "mamba":
-        mix, new_state = S.mamba_fwd(cfg, p["mixer"], h, state)
-    elif mixer == "mlstm":
-        mix, new_state = X.mlstm_fwd(cfg, p["mixer"], h, state)
-    elif mixer == "slstm":
-        mix, new_state = X.slstm_fwd(cfg, p["mixer"], h, state)
+        mix, state = A.attn_fwd(cfg, p["mixer"], h, positions, state,
+                                proj=proj_attn, layer=layer)
+    elif mixer in RECURRENT_FWD:
+        st = None if state is None else jax.tree.map(lambda a: a[layer], state)
+        mix, st = RECURRENT_FWD[mixer](cfg, p["mixer"], h, st)
+        if state is not None:
+            state = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                    a, n.astype(a.dtype), layer, 0), state, st)
     else:
         raise ValueError(mixer)
     x = x + mix
@@ -168,7 +180,7 @@ def _apply_layer(cfg, mixer, ffn, p, x, positions, state, capacity,
         else:
             y = mlp_fwd(cfg, p["ffn"], h2, proj=proj_ffn)
         x = x + y
-    return x, new_state, aux
+    return x, state, aux
 
 
 def forward(
@@ -233,14 +245,13 @@ def forward(
         # closes over the concrete weight bank).
         lb = jnp.zeros((), jnp.float32)
         dropped = jnp.zeros((), jnp.float32)
-        per_pos_states: list = [[] for _ in period]
+        st = list(state) if have_state else None
         for r in range(cfg.n_periods):
             for j, (mixer, ffn) in enumerate(period):
                 p_j = jax.tree.map(lambda a, r=r: a[r], params["period"][j])
-                st_j = (jax.tree.map(lambda s, r=r: s[r], state[j])
-                        if have_state else None)
-                x, new_st, aux = _apply_layer(
-                    cfg, mixer, ffn, p_j, x, positions, st_j, capacity,
+                x, st_j, aux = _apply_layer(
+                    cfg, mixer, ffn, p_j, x, positions,
+                    st[j] if have_state else None, r, capacity,
                     proj_attn=trunk.projector(j, r, "attn", trunk_isa,
                                               offsets=trunk_offsets),
                     proj_ffn=trunk.projector(j, r, "ffn", trunk_isa,
@@ -248,36 +259,36 @@ def forward(
                 )
                 x = constrain(x, ("dp", None, None))
                 if have_state:
-                    per_pos_states[j].append(new_st)
+                    st[j] = st_j
                 if aux is not None:
                     lb = lb + aux["lb_loss"]
                     dropped = dropped + aux["dropped"]
-        new_state = ([jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
-                      for reps in per_pos_states] if have_state else 0)
     else:
+        # The state rides in the scan's carry: each layer reads its repeat by
+        # index and writes back only what it changed, so a donated state is
+        # updated in place.
         def period_body(carry, xs):
-            x, lb, dropped = carry
-            p_stack, st_stack = xs
-            new_states = []
+            x, lb, dropped, st = carry
+            p_stack, r = xs
             for j, (mixer, ffn) in enumerate(period):
-                st_j = st_stack[j] if have_state else None
-                x, new_st, aux = _apply_layer(
-                    cfg, mixer, ffn, p_stack[j], x, positions, st_j, capacity
-                )
+                x, st_j, aux = _apply_layer(
+                    cfg, mixer, ffn, p_stack[j], x, positions,
+                    st[j] if have_state else None, r, capacity)
                 # anchor sharding propagation inside the while body (GSPMD
                 # does not reliably propagate through scan+remat)
                 x = constrain(x, ("dp", None, None))
-                new_states.append(new_st if have_state else st_j)
+                if have_state:
+                    st[j] = st_j
                 if aux is not None:
                     lb = lb + aux["lb_loss"]
                     dropped = dropped + aux["dropped"]
-            return (x, lb, dropped), (new_states if have_state else 0)
+            return (x, lb, dropped, st), None
 
-        carry0 = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-        xs = (params["period"],
-              state if have_state else jnp.zeros((cfg.n_periods,)))
+        carry0 = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
+                  list(state) if have_state else None)
+        xs = (params["period"], jnp.arange(cfg.n_periods))
         body = jax.checkpoint(period_body) if remat else period_body
-        (x, lb, dropped), new_state = jax.lax.scan(body, carry0, xs)
+        (x, lb, dropped, st), _ = jax.lax.scan(body, carry0, xs)
 
     if logits_mode == "last":
         # Serving prefill: only the last position's logits are consumed;
@@ -292,7 +303,7 @@ def forward(
         logits = x.astype(jnp.float32)
     n_moe = max(1, sum(1 for _, f in cfg.layer_plan() if f == "moe"))
     aux = {"lb_loss": lb / n_moe, "dropped": dropped / n_moe}
-    return ForwardOut(logits=logits, state=new_state if have_state else None, aux=aux)
+    return ForwardOut(logits=logits, state=st, aux=aux)
 
 
 def balanced_lm_head(cfg: ModelConfig, params: dict, dispatcher):

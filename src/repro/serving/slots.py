@@ -45,9 +45,9 @@ def _adopt(big, small, slot):
 def _reset_slot(big, slot):
     """Zero a released slot's cache index.  While the slot stays free its
     idx still drifts (+1 per decode step, like every row); that is
-    harmless — cache writes clamp at the buffer edge and the next adopt
-    overwrites the whole row — but resetting here keeps the drift from
-    accumulating across occupancies."""
+    harmless — cache writes past the buffer edge are dropped and the next
+    adopt overwrites the whole row — but resetting here keeps the drift
+    from accumulating across occupancies."""
 
     def fix(leaf):
         if isinstance(leaf, KVCache):

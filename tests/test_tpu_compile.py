@@ -13,6 +13,7 @@ test worker imports this file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,8 @@ from repro.serving.engine import step_programs
 # granite-8b projections (K -> N): attention q/o, MLP up, MLP down, LM head
 GRANITE_SHAPES = [(4096, 4096), (4096, 14336), (14336, 4096), (4096, 49152)]
 SLOTS, MAX_SEQ = 16, 2048
+# the benchmark's granite-8b.chat cell: 16 layers, 32 slots x 1024 positions
+CELL_LAYERS, CELL_SLOTS, CELL_SEQ = 16, 32, 1024
 
 
 @pytest.fixture(scope="module")
@@ -101,3 +104,26 @@ def test_engine_decode_step_compiles(one_chip):
     kv_bytes = 2 * 2 * SLOTS * cfg.n_kv_heads * MAX_SEQ * cfg.hd * 2
     assert mem.argument_size_in_bytes > kv_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_engine_decode_appends_to_cache_in_place(one_chip):
+    """The plain engine decode step at the chat cell's shapes: the layer
+    scan carries the donated KV stack and appends each layer's rows into
+    it, so the compiled step holds no second copy of the cache (today's
+    temporaries are well under 1% of it) and copies no stacked buffer."""
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=CELL_LAYERS)
+    params = _on(one_chip, abstract_params(cfg))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: init_slot_state(cfg, CELL_SLOTS, CELL_SEQ)))
+    tok = jax.ShapeDtypeStruct((CELL_SLOTS, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((CELL_SLOTS,), jnp.int32, sharding=one_chip)
+    _, _, decode = step_programs(cfg)
+    compiled = decode.lower(params, tok, state, pos).compile()
+    mem = compiled.memory_analysis()
+    stack = (CELL_LAYERS, CELL_SLOTS, cfg.n_kv_heads, CELL_SEQ, cfg.hd)
+    kv_bytes = 2 * 2 * CELL_LAYERS * CELL_SLOTS * cfg.n_kv_heads * CELL_SEQ * cfg.hd
+    assert mem.alias_size_in_bytes >= kv_bytes
+    assert mem.temp_size_in_bytes < 0.1 * kv_bytes
+    shape = ",".join(map(str, stack))
+    copies = re.findall(rf"= bf16\[{shape}\]\S* copy\(", compiled.as_text())
+    assert not copies
