@@ -3,9 +3,13 @@
 A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its engine shape and its check sit in
-``bench/cells/<cell>.json``.  Every metric is read by a reader of its own,
-``bench/metrics/<name>.py`` (the part of the name before the first dot), so
-that a new cell or metric is new files and entries, never an edit here.
+``bench/cells/<cell>.json``.  The mix's ``arrivals`` names its arrival
+process, ``bench/arrivals/<arrivals>.py`` (``bench/traffic.py`` says what
+one gives: due times relative to the window's opening, the same work for
+every seed, prompt ids from the seed).  Every metric is read by a reader of
+its own, ``bench/metrics/<name>.py`` (the part of the name before the first
+dot).  So a new cell, traffic or metric is new files and entries, never an
+edit here.
 
 One run:
 
@@ -16,8 +20,9 @@ One run:
 2. the window: requests are submitted when due, the dispatcher is stepped
    while it has work and the host sleeps while it has none; each token is
    stamped with ``time.perf_counter()`` when the step that made it returns;
-3. after it: requests due in the window that still lack a first token are
-   followed until they get one;
+3. after it: requests due in the window, or admitted in it, that still
+   lack a first token are followed until they get one; the log says how
+   many submitted requests the window left unadmitted (a backlog's rest);
 4. the check: the requests the run finished are sampled from the seed, the
    longest among them, and each served token is compared with the plain
    reference (``bench/references/<reference>.py``) once the engine is gone.
@@ -30,9 +35,7 @@ that admission works; no metric reads it.
 
 from __future__ import annotations
 
-import functools
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -45,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import traffic, xtrace
+from . import load_module, traffic, xtrace
 from .model import Shape, load_config, make_weights, program_config, \
     program_params
 
@@ -92,23 +95,13 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
         per_layer=[m for m in spec["per_layer"] if _reports(m, name)])
 
 
-@functools.lru_cache(maxsize=None)
-def _load_module(path: Path):
-    """A file of the benchmark, loaded once per process."""
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem}".replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def reader(metric: str) -> Callable:
     """``read(run)`` of ``bench/metrics/<metric before its first dot>.py``."""
-    return _load_module(BENCH / "metrics" / f"{metric.split('.')[0]}.py").read
+    return load_module(BENCH / "metrics" / f"{metric.split('.')[0]}.py").read
 
 
 def reference(name: str):
-    return _load_module(BENCH / "references" / f"{name}.py")
+    return load_module(BENCH / "references" / f"{name}.py")
 
 
 # ------------------------------------------------------------- records ---
@@ -178,7 +171,8 @@ class Run:
         if len(steps) != len(execs):
             raise ValueError(
                 f"{program}: {len(execs)} device executions in the traced "
-                f"window, {len(steps)} host steps that ran it")
+                f"window, {len(steps)} host steps that ran it; "
+                f"{self.trace.edges(program)}")
         return list(zip(steps, execs)) or None
 
 
@@ -355,6 +349,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         compiles.on = False
         full_gcs = gc.get_stats()[2]["collections"] - full_gcs
     window_steps = drv.steps[lead_in:]
+    backlog_left = len(drv.waiting)
 
     # what the window owes a first token: requests due in it, and those it
     # admitted; they are followed until they get one
@@ -373,7 +368,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                                                      window_steps[1:])),
                       default=0.0)
     log(f"[bench] window {t_close - t_open:.3f}s: {len(window_steps)} steps, "
-        f"{len(owed)} requests owed a first token; programs compiled in the "
+        f"{len(owed)} requests owed a first token; backlog left: "
+        f"{backlog_left} of {sum(tr.submit_t is not None for tr in everyone)}"
+        f" submitted not admitted; programs compiled in the "
         f"window: {compiles.count}; full collections in the window: "
         f"{full_gcs}; generator late by p50 "
         f"{float(np.median(late)) if late else 0.0:.3f} ms, max "
@@ -442,16 +439,22 @@ def _drive(drv: Driver, pending: List[Tracked], until: float, *,
     """Submit ``pending`` as they fall due and step until ``until``;
     returns the time the last step ended.  With ``trace_at`` the profiler
     records from then, between steps, to the end: stopping it takes
-    seconds, so that falls after the window."""
+    seconds, so that falls after the window.  The traced window opens one
+    step after the profiler starts, so that the device's tracer records
+    the window's first step whole."""
     disp = drv.disp
     i, n = 0, len(pending)
+    profiled_from = None           # steps taken when the profiler started
     window_span = None
     now = clock()
     while now < until:
-        if trace_at is not None and window_span is None and now >= trace_at:
+        if trace_at is not None and profiled_from is None and now >= trace_at:
             shutil.rmtree(trace_dir, ignore_errors=True)
             jax.profiler.start_trace(str(trace_dir),
                                      profiler_options=_profile_options())
+            profiled_from = len(drv.steps)
+        if (window_span is None and profiled_from is not None
+                and len(drv.steps) > profiled_from):
             window_span = jax.profiler.TraceAnnotation(xtrace.WINDOW_SPAN)
             window_span.__enter__()
             drv.tracing = True
@@ -462,7 +465,7 @@ def _drive(drv: Driver, pending: List[Tracked], until: float, *,
             drv.step()
         else:
             wake = [pending[i].due if i < n else until, until]
-            if trace_at is not None and window_span is None:
+            if trace_at is not None and profiled_from is None:
                 wake.append(trace_at)
             with jax.profiler.TraceAnnotation("sleep"):
                 time.sleep(max(0.0, min(wake) - clock()))
@@ -470,13 +473,14 @@ def _drive(drv: Driver, pending: List[Tracked], until: float, *,
     while i < n and pending[i].due <= now:   # due during the last step
         drv.submit(pending[i])
         i += 1
-    if drv.tracing:
+    if profiled_from is not None:
         _stop_trace(drv, window_span)
     return now
 
 
 def _stop_trace(drv: Driver, span) -> None:
-    span.__exit__(None, None, None)
+    if span is not None:
+        span.__exit__(None, None, None)
     jax.profiler.stop_trace()
     drv.tracing = False
 

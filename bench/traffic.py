@@ -1,19 +1,25 @@
 """Seeded traffic from a mix file: one generator for every mix.
 
 A mix (``bench/traffic/<name>.json``) gives the arrival process and the
-length distributions.  The seed picks the order, not the work: the
-pre-roll and the window each get a fixed multiset of prompt lengths,
-answer lengths and inter-arrival gaps (midpoint quantiles of the
-distributions), put in an order drawn from the seed, and prompt token ids
-drawn from the seed.  Two seeds therefore offer the window the same
-requests and differ only in how they are interleaved.
+length distributions.  The seed never changes the work the window sees:
+the lengths and due times are a fixed multiset (midpoint quantiles of the
+distributions), and the prompt token ids are drawn from the seed.
 
-Arrivals (``poisson``): exponential gaps at ``rate`` req/s, from
-``-preroll_s`` to 0 and from 0 to the end of the window; due times are
-relative to the window's opening.  With ``strata`` k > 1 the order is
-stratified: each quantity's values are split into k bands (strata) of
-equal count, and every k consecutive requests hold one value of each band,
-so that no stretch of the window is much more loaded than another and the
+The mix's ``arrivals`` names an arrival process,
+``bench/arrivals/<arrivals>.py``, so that a new process is a new file and
+never an edit here.  Its ``generate(mix, rng, seconds, vocab)`` returns
+the run's :class:`Item` list, every due time relative to the window's
+opening (the pre-roll is before 0); it offers the window the same work
+for every seed, draws prompt ids from ``rng``, and uses the shared helpers
+below.  Where the window sees all of its part of the traffic, as with
+open-loop arrivals, the seed may also draw the order: ``poisson``
+reorders one multiset of requests in the pre-roll and one in the window.
+Where it sees only a part, as with a backlog whose window serves the front
+of the queue, the order is fixed, since the seed would otherwise choose
+the part.  With ``strata`` k > 1, :func:`stratified` draws an order in
+which each quantity's values are split into k bands (strata) of equal
+count, and every k consecutive requests hold one value of each band, so
+that no stretch of the window is much more loaded than another and the
 tails do not swing with where the seed puts the long prompts and short
 gaps.  Within a block of k the order is random.
 """
@@ -21,11 +27,16 @@ gaps.  Within a block of k the order is random.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from pathlib import Path
 from statistics import NormalDist
 
 import numpy as np
 
+from . import load_module
+
 __all__ = ["Item", "generate", "quantile_lengths", "stratified"]
+
+ARRIVALS = Path(__file__).resolve().parent / "arrivals"
 
 
 @dataclass(frozen=True)
@@ -47,13 +58,6 @@ def quantile_lengths(spec: dict, n: int) -> np.ndarray:
     return np.clip(np.rint(raw), spec["min"], spec["max"]).astype(np.int64)
 
 
-def _exponential_gaps(n: int, span: float) -> np.ndarray:
-    """``n`` midpoint quantiles of an exponential, in increasing order,
-    scaled to sum to ``span``."""
-    q = -np.log1p(-(np.arange(n) + 0.5) / n)
-    return q * span / q.sum()
-
-
 def stratified(values: np.ndarray, k: int, rng) -> np.ndarray:
     """``values`` (increasing) in an order drawn from ``rng`` in which every
     ``k`` consecutive positions hold one value of each of ``k`` bands of
@@ -73,23 +77,12 @@ def stratified(values: np.ndarray, k: int, rng) -> np.ndarray:
 
 def generate(mix: dict, seed: int, seconds: float, vocab: int) -> list:
     """The requests of one run, sorted by due time."""
-    if mix["arrivals"] != "poisson":
-        raise ValueError(f"unknown arrival process {mix['arrivals']!r}")
-    rng = np.random.default_rng(seed)
-    k = int(mix.get("strata", 1))
-    preroll = float(mix.get("preroll_s", 0.0))
-    items = []
-    for start, span in ((-preroll, preroll), (0.0, float(seconds))):
-        n = int(round(mix["rate"] * span))
-        if n == 0:
-            continue
-        gaps = stratified(_exponential_gaps(n, span), k, rng)
-        due = start + np.concatenate([[0.0], np.cumsum(gaps[:-1])])
-        prompts = stratified(quantile_lengths(mix["prompt"], n), k, rng)
-        outputs = stratified(quantile_lengths(mix["output"], n), k, rng)
-        items += [Item(due=float(due[i]),
-                       prompt=rng.integers(0, vocab, size=int(prompts[i]),
-                                           dtype=np.int32),
-                       max_new=int(outputs[i]))
-                  for i in range(n)]
-    return items
+    name = mix["arrivals"]
+    path = ARRIVALS / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in ARRIVALS.glob("*.py"))
+        raise ValueError(f"unknown arrival process {name!r}; "
+                         f"bench/arrivals/ has {known}")
+    items = load_module(path).generate(mix, np.random.default_rng(seed),
+                                       float(seconds), vocab)
+    return sorted(items, key=lambda it: it.due)

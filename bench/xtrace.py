@@ -58,13 +58,41 @@ class Trace:
         return (self.window[1] - self.window[0]) * 1e-9
 
     def executions(self, name: str, chip: int = 0) -> List[Event]:
-        """Executions of the jitted function ``name`` on ``chip`` that start
-        inside the window, in order.  By the start: the device's clock is
-        aligned to the host's only to some tenths of a millisecond, so the
-        window's last execution may seem to end after the window does."""
+        """Executions of the jitted function ``name`` on ``chip`` whose
+        midpoint lies inside the window, in order.  The device's clock is
+        aligned to the host's only to some tenths of a millisecond, so an
+        execution of the window's first or last step may seem to start
+        before the window or end after it.  Each lies inside the host step
+        that sent it and waited for it, a step of milliseconds, and the
+        window opens one step after the profiler starts: the midpoint of
+        every execution of the window's steps lies inside it, and that of
+        any other outside."""
         lo, hi = self.window
         return [e for e in self.modules.get(chip, [])
-                if e.module == name and lo <= e.start <= hi]
+                if e.module == name and lo <= (e.start + e.end) / 2 <= hi]
+
+    def edges(self, name: str, chip: int = 0) -> str:
+        """Where the executions of ``name`` and the host's steps lie
+        against the window's edges, in ms: what a disagreement between
+        device and host counts is read from."""
+        lo, hi = self.window
+        evs = [e for e in self.modules.get(chip, []) if e.module == name]
+        steps = [e for e in self.spans if e.name == "step"
+                 and lo <= e.start <= hi]
+        inside = self.executions(name, chip)
+        out = [f"{len(evs)} {name} in the profile, "
+               f"{sum(e.start < lo for e in evs)} starting before the "
+               f"window, {sum(e.end > hi for e in evs)} ending after it"]
+        if inside:
+            first, last = inside[0], inside[-1]
+            out.append(f"the first inside runs {(first.start - lo) / 1e6:.3f}"
+                       f" to {(first.end - lo) / 1e6:.3f} from the opening, "
+                       f"the last ends {(hi - last.end) / 1e6:.3f} before "
+                       f"the close")
+        if steps:
+            out.append(f"host steps from {(steps[0].start - lo) / 1e6:.3f} "
+                       f"to {(steps[-1].end - lo) / 1e6:.3f}")
+        return "; ".join(out)
 
     def busy_s(self) -> Optional[float]:
         """Seconds in which any operation ran, averaged over the chips that

@@ -6,10 +6,11 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 import types
 
 import pytest
-from conftest import REPO, SPEC, run_tiny, tiny_cell
+from conftest import PEAKS, REPO, SPEC, run_tiny, tiny_cell
 
 from bench import harness
 
@@ -40,6 +41,40 @@ def test_open_loop_cell():
     assert line["compared"]["widest_gap"]["limit"] == 0.5
 
 
+BACKLOG_MIX = dict(arrivals="backlog", count=1000, preroll_s=0.5,
+                   prompt=dict(dist="lognormal", median=40, sigma=0.5, min=16,
+                               max=90),
+                   output=dict(dist="lognormal", median=12, sigma=0.5, min=4,
+                               max=30))
+
+
+def test_backlog_cell():
+    """A ChatGLM-like tiny shape (two KV heads, q/k/v bias, rotary on half
+    of each head) serving a backlog due before the window: correct, and
+    every request the window admitted got its first token."""
+    cell = tiny_cell(mix=BACKLOG_MIX)
+    assert cell.shape.qkv_bias and cell.shape.rope_fraction == 0.5
+    seen, logged = {}, []
+    res = harness.run_cell(cell, 2 ** 33 + 17, 1.0, False, peaks=PEAKS,
+                           t_start=time.perf_counter(), log=logged.append,
+                           on_run=lambda run: seen.update(run=run))
+    line = _check_line(res, cell)
+    assert line["correct"] and line["failed"] == 0
+    run = seen["run"]
+    assert len(run.requests) == 1000
+    assert not any(run.in_window(tr.due) for tr in run.requests)
+    assert all(tr.submit_t < run.t_open for tr in run.requests)
+    admitted = [tr for tr in run.requests if run.in_window(tr.lane_t)]
+    assert line["attempted"] == len(admitted) > 0
+    assert line["metrics"]["served_tok_s"]["value"] > 0
+    assert any(len(tr.token_t) > 1 for tr in run.requests)
+    left = [tr for tr in run.requests
+            if tr.lane_t is None or tr.lane_t > run.t_close]
+    assert left, "the backlog emptied: the window saw no full queue"
+    assert any(f"backlog left: {len(left)} of 1000 submitted" in m
+               for m in logged)
+
+
 def test_traced_run_reports_per_layer_metrics(tmp_trace):
     cell = tiny_cell()
     res = run_tiny(cell, trace=True, trace_dir=tmp_trace)
@@ -49,6 +84,23 @@ def test_traced_run_reports_per_layer_metrics(tmp_trace):
     assert set(res["metrics"]) == {"step_mfu"}
     assert res["device"]["window_s"] > 0
     assert len(res["breakdown"]["idle_gaps"]) <= 10
+
+
+def test_new_cell_reads_its_own_per_layer_entries(tmp_trace):
+    """A cell that ``BENCHMARK.json``'s per-layer entries do not list gets
+    none of them; entries of its own, ``<metric>.<suffix>``, are read by
+    ``<metric>``'s reader, so a new cell needs new entries and no code."""
+    cell = tiny_cell(name="tiny.docbatch", mix=BACKLOG_MIX)
+    assert cell.per_layer == []
+    assert {m["name"] for m in cell.end_to_end} == {"setup_s",
+                                                    "served_tok_s"}
+    cell.per_layer = [dict(m, name=f"{m['name']}.batch", moves="served_tok_s",
+                           workloads=[cell.name]) for m in SPEC["per_layer"]]
+    res = run_tiny(cell, trace=True, trace_dir=tmp_trace)
+    assert res["correct"]
+    # the CPU has no device plane: the host-clock readers find numbers
+    assert "step_mfu.batch" in res["metrics"]
+    assert set(res["metrics"]) <= {"step_mfu.batch", "queue_wait_ms.batch"}
 
 
 def test_no_limit_is_not_correct():

@@ -77,8 +77,12 @@ def test_counts_that_disagree_read_nothing():
     tr.modules[0] = tr.modules[0][:3]           # one decode missing
     run = _run(tr)
     for name in ("decode_step_ms", "decode_roofline"):
-        with pytest.raises(ValueError, match="_decode: 1 device"):
+        with pytest.raises(ValueError, match="_decode: 1 device") as err:
             harness.reader(name)(run)
+        # the message says where the executions and steps lie
+        assert ("1 _decode in the profile, 0 starting before the window"
+                in str(err.value))
+        assert "host steps from 0.000 to 95.000" in str(err.value)
     assert harness.reader("prefill_chunk_ms")(run) == pytest.approx(12.0)
 
 
@@ -88,6 +92,27 @@ def test_execution_ending_past_the_window_is_the_last_steps():
     pairs with that step."""
     tr = _trace()
     tr.window = (0.0, 79.5 * MS)                # the last decode ends at 80
+    run = _run(tr)
+    assert harness.reader("decode_step_ms")(run) == pytest.approx(20.0)
+
+
+def test_execution_starting_before_the_window_is_the_first_steps():
+    """Likewise the first traced step's first execution may seem to start
+    just before the window does; it still pairs with that step."""
+    tr = _trace()
+    tr.window = (2.5 * MS, 100 * MS)            # the first piece starts at 2
+    run = _run(tr)
+    assert harness.reader("prefill_chunk_ms")(run) == pytest.approx(12.0)
+    assert harness.reader("decode_step_ms")(run) == pytest.approx(20.0)
+
+
+def test_execution_of_the_step_before_the_window_is_not_its():
+    """The window opens one step after the profiler starts.  That step's
+    execution lies in the profile and may seem to end just inside the
+    window; it pairs with no step of the window."""
+    tr = _trace()
+    lead = xtrace.Event("jit__decode(7)", -19.5 * MS, 0.5 * MS, "_decode")
+    tr.modules[0] = [lead] + tr.modules[0]
     run = _run(tr)
     assert harness.reader("decode_step_ms")(run) == pytest.approx(20.0)
 
@@ -114,15 +139,21 @@ def test_union():
 
 def test_profiler_file_on_the_cpu(tmp_trace):
     """A traced tiny run writes a profile; the reader finds the window and
-    the harness's host spans in it (the CPU has no device plane)."""
+    the harness's host spans in it (the CPU has no device plane).  The
+    window opens one step after the profiler starts: that step, and no
+    other, lies in the profile before it."""
     seen = {}
     run_tiny(tiny_cell(), trace=True, trace_dir=tmp_trace,
              on_run=lambda run: seen.update(run=run))
     tr = seen["run"].trace
     assert tr is not None and tr.window_s > 0
     assert {"step", "observe"} <= {sp.name for sp in tr.spans}
-    assert all(tr.window[0] <= sp.start for sp in tr.spans
-               if sp.name == "step")
+    before = [sp for sp in tr.spans
+              if sp.name == "step" and sp.start < tr.window[0]]
+    assert len(before) == 1 and before[0].end <= tr.window[0]
+    inside = [sp for sp in tr.spans if sp.name == "step"
+              and tr.window[0] <= sp.start <= tr.window[1]]
+    assert len(inside) == len(seen["run"].traced_steps())
     assert tr.busy_s() is None and tr.modules == {}
 
 
@@ -175,3 +206,50 @@ def test_kept_trace_breakdown():
     assert len(b["idle_gaps"]) == 10
     assert all(name.split(":")[0] in xtrace.HOST_SPANS + ("other",)
                for name, _ in b["idle_gaps"])
+
+
+# --------------------- a backlog run whose first execution precedes it --
+EDGE = REPO / "bench" / "testdata" / "granite-8b.backlog.edge"
+EDGE_METRICS = ("prefill_chunk_ms", "decode_step_ms", "decode_roofline",
+                "step_mfu", "device_idle")
+
+
+def _edge_run():
+    """A traced backlog run on a v5e (granite-8b at 16 layers, 6 slots x
+    8192; seed 2200001638) whose first traced step's ``_decode`` starts
+    0.35 ms before the window opens on the device's clock, with the host's
+    steps and the metrics the chip read."""
+    kept = json.loads((EDGE / "steps.json").read_text())
+    cell = harness.load_cell("granite-8b.chat")
+    cell.engine = dict(cell.engine, slots=6, max_seq=8192, prefill_chunk=256)
+    run = harness.Run(
+        cell=cell, peaks=kept["peaks"], setup_s=0.0, t_open=0.0,
+        t_close=0.0, requests=[],
+        steps=[harness.Step(**s) for s in kept["steps"]],
+        trace=xtrace.load(EDGE / "trace.xplane.pb.xz"))
+    return run, kept["metrics"]
+
+
+def test_kept_edge_trace_first_execution_precedes_the_window():
+    """Taken by their start, the executions would miss the first step's
+    ``_decode``, and the reader would raise as it once did on the chip
+    ("143 device executions ..., 144 host steps"); taken by their
+    midpoint, they pair with the host's steps, and the lead step's, whole
+    in the profile before the window, is not taken."""
+    run, _ = _edge_run()
+    tr = run.trace
+    lo, hi = tr.window
+    decodes = [e for e in tr.modules[0] if e.module == "_decode"]
+    inside = tr.executions("_decode")
+    assert inside[0].start < lo < inside[0].end
+    assert len([e for e in decodes if e.end <= lo]) == 1
+    by_start = [e for e in decodes if lo <= e.start <= hi]
+    host = [s for s in run.steps if s.decode_kv]
+    assert len(inside) == len(host) == len(by_start) + 1
+
+
+@pytest.mark.parametrize("name", EDGE_METRICS)
+def test_kept_edge_trace_reads_as_on_the_chip(name):
+    run, recorded = _edge_run()
+    assert harness.reader(name)(run) == pytest.approx(recorded[name],
+                                                      rel=1e-12)
